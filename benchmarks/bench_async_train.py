@@ -53,7 +53,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import sys
-import tempfile
 import time
 
 import jax
@@ -63,6 +62,7 @@ from repro.configs import REGISTRY, reduced
 from repro.core.manager import TaskSpec
 from repro.core.runtime import MARLaaSRuntime, RuntimeConfig
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 import repro.rollout.engine as eng_mod
 import repro.rollout.prefill as pf_mod
@@ -87,19 +87,6 @@ GATE_TRACE_RESIDUAL = 0.01    # max |Σcomponents - e2e| / e2e per episode
 TRACE_ARTIFACT = "BENCH_async_train_trace.json"
 
 _STATE = {}
-
-
-def _compile_cache():
-    """Persistent XLA compile cache for this process: the engine jits
-    per-instance closures, so each fresh runtime re-traces every shape
-    bucket — with the cache, only the warm pass compiles and the measured
-    arms load cached executables in milliseconds."""
-    if _STATE.get("cache"):
-        return
-    jax.config.update("jax_compilation_cache_dir",
-                      tempfile.mkdtemp(prefix="bench_async_train_xla_"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _STATE["cache"] = True
 
 
 def _bias_sampler():
@@ -134,7 +121,7 @@ def _model():
 def _runtime(async_train: bool, trace: bool = False):
     """One arm's runtime over the mixed 16-tenant workload. Both arms build
     from the same base params and the same per-tenant seeds."""
-    _compile_cache()
+    enable_compile_cache()
     _bias_sampler()
     cfg, params = _model()
     rt = MARLaaSRuntime(cfg, params, RuntimeConfig(

@@ -41,7 +41,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import sys
-import tempfile
 import time
 
 import jax
@@ -52,6 +51,7 @@ from repro.core.chaos import ChaosConfig
 from repro.core.manager import TaskSpec
 from repro.core.runtime import MARLaaSRuntime, RuntimeConfig
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 import repro.rollout.engine as eng_mod
 import repro.rollout.prefill as pf_mod
@@ -81,15 +81,6 @@ CHAOS = ChaosConfig(
     max_faults_per_site=2)        # ...all exactly twice, then never again
 
 _STATE = {}
-
-
-def _compile_cache():
-    if _STATE.get("cache"):
-        return
-    jax.config.update("jax_compilation_cache_dir",
-                      tempfile.mkdtemp(prefix="bench_chaos_xla_"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _STATE["cache"] = True
 
 
 def _bias_sampler():
@@ -122,7 +113,7 @@ def _model():
 
 
 def _runtime(chaos):
-    _compile_cache()
+    enable_compile_cache()
     _bias_sampler()
     cfg, params = _model()
     rt = MARLaaSRuntime(cfg, params, RuntimeConfig(

@@ -115,6 +115,7 @@ def save_checkpoint(directory: str, mgr: MultiTaskManager,
                 "status": st.status,
                 "abandoned": st.abandoned,
                 "reward_history": st.reward_history,
+                "loss_history": st.loss_history,
                 "counters": {k: getattr(st, k) for k in _TASK_COUNTERS},
                 "has_adapters": st.adapters is not None,
                 "has_opt": st.opt_state is not None,
@@ -285,6 +286,7 @@ def load_checkpoint(path: str, mgr: MultiTaskManager) -> MultiTaskManager:
                            rollout_issued_version=entry["version"] - 1,
                            submitted_at=mgr.clock())
             st.reward_history = list(entry.get("reward_history", []))
+            st.loss_history = list(entry.get("loss_history", []))
             for k, v in entry.get("counters", {}).items():
                 setattr(st, k, v)
             mgr.tasks[spec.task_id] = st

@@ -90,6 +90,8 @@ class TaskState:
     last_step_at: Optional[float] = None
     step_times: List[float] = field(default_factory=list)
     reward_history: List[float] = field(default_factory=list)
+    loss_history: List[float] = field(default_factory=list)  # GRPO loss
+                                                             # per commit
 
     @property
     def done(self) -> bool:
@@ -688,7 +690,7 @@ class MultiTaskManager:
 
     # -- Algorithm 1, line 15: commit θ,φ^(v+1) ---------------------------
     def commit(self, task_id: str, adapters, opt_state, trained_version: int,
-               reward_mean: float = 0.0):
+               reward_mean: float = 0.0, loss: Optional[float] = None):
         with self._lock:
             st = self.tasks[task_id]
             lag = st.version - trained_version
@@ -707,6 +709,8 @@ class MultiTaskManager:
             st.step_times.append(now)
             st.last_step_at = now
             st.reward_history.append(float(reward_mean))
+            if loss is not None:
+                st.loss_history.append(loss)
             if st.done:
                 st.status = "finished"
                 self._purge_task_queues(task_id)

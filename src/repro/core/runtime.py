@@ -92,6 +92,7 @@ from __future__ import annotations
 import random
 import threading
 import time
+import zlib
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -114,6 +115,13 @@ from .manager import MultiTaskManager, TaskSpec
 from .metrics import MetricsRecorder
 from .supervisor import (ABANDONED, CLOSED, HALF_OPEN, OPEN,  # noqa: F401
                          TenantBreaker, join_or_raise)
+
+
+def task_seed(seed: int, task_id: str) -> int:
+    """Per-tenant 31-bit seed for the LoRA init key and the prompt datagen.
+    crc32, not hash(): str hashing is salted per process, so the same seed
+    would give different adapters and prompts in every process."""
+    return zlib.crc32(f"{seed}:{task_id}".encode()) & 0x7FFFFFFF
 
 
 @dataclass
@@ -199,7 +207,8 @@ class RuntimeConfig:
     max_len: int = 96
     use_kernel: bool = False
     seed: int = 0
-    rollout_pool_devices: int = 1     # metric bookkeeping (host has 1 CPU)
+    rollout_pool_devices: int = 1     # metric bookkeeping only: the runtime
+                                      # runs every stage on one device
     train_pool_devices: int = 1
     checkpoint_dir: Optional[str] = None
     checkpoint_every: int = 0         # commits between snapshots (0 = off)
@@ -395,7 +404,8 @@ class MARLaaSRuntime:
     # -- task submission ---------------------------------------------------
     def submit_task(self, spec: TaskSpec, adapters=None, opt_state=None):
         if adapters is None:
-            key = jax.random.PRNGKey(hash(spec.task_id) % (2 ** 31))
+            key = jax.random.PRNGKey(task_seed(self.rcfg.seed,
+                                               spec.task_id))
             adapters = init_lora(key, self.cfg)
         tc = self._tc(spec)
         if opt_state is None:
@@ -403,7 +413,7 @@ class MARLaaSRuntime:
         self.mgr.submit(spec, adapters, opt_state)
         self.envs[spec.task_id] = make_env(spec.env_name)
         self.datagens[spec.task_id] = random.Random(
-            hash((self.rcfg.seed, spec.task_id)) % (2 ** 31))
+            task_seed(self.rcfg.seed, spec.task_id))
 
     def _tc(self, spec: TaskSpec) -> TrainConfig:
         # the importance-weight correction only activates when stale
@@ -870,7 +880,8 @@ class MARLaaSRuntime:
         self.rec.record("train", "train", tb.task_id, t0, t1,
                         self.rcfg.train_pool_devices)
         self.mgr.commit(tb.task_id, new_adapters, new_opt, trained_version,
-                        reward_mean=float(np.mean(tb.rewards)))
+                        reward_mean=float(np.mean(tb.rewards)),
+                        loss=float(metrics["loss"]))
         if self.tracer is not None:
             t_commit = self.tracer.now()
             self.tracer.span(("train", "trainer"), tb.task_id, t0, t_commit,
@@ -1118,7 +1129,7 @@ class MARLaaSRuntime:
         for tid, st in self.mgr.task_items():
             self.envs[tid] = make_env(st.spec.env_name)
             self.datagens[tid] = random.Random(
-                hash((self.rcfg.seed, tid)) % (2 ** 31))
+                task_seed(self.rcfg.seed, tid))
         self.mgr.rebind_episode_envs(self.envs)
 
     def run_with_recovery(self, timeout_s: float = 600.0,

@@ -6,5 +6,7 @@
 #                   logical page DMAs straight from its pooled location
 #   token_logprob — fused LSE+gather+entropy over big vocabs (GRPO training)
 # Each has ops.py wrappers and ref.py pure-jnp oracles; validated in
-# interpret mode on CPU, targeted at TPU v5e tile sizes.
+# interpret mode on CPU, compiled for a described TPU v5e by
+# tests/test_tpu_compile.py, and checked against the oracles on the chip
+# by chip_smoke.py.
 from . import ops, ref

@@ -10,7 +10,15 @@ so  logprob = logit_tgt − (m + log l)   and  entropy = (m + log l) − s/l.
 
 Grid (row_blocks, V_blocks, K_blocks): K innermost accumulates the logits
 tile h·W in VMEM scratch; at the last K slice the online stats fold the
-tile in, and the target gather hits at most one tile per row.
+tile in, and the target gather hits at most one tile per row. Targets ride
+in as a [BM, 1] VMEM block (vector loads from the scalar-prefetch SMEM
+channel are refused by Mosaic). A vocabulary that is not a multiple of 128
+is zero-padded to one, vocab tiles are multiples of 128 lanes, and every
+column at or past V — the padding and the out-of-bounds tail of a last
+partial tile alike — is masked to −∞ before the stats fold it in.
+
+The kernel has no VJP of its own; ``ops.token_logprob`` pairs it with the
+chunked jnp backward through ``jax.custom_vjp``.
 """
 from __future__ import annotations
 
@@ -25,14 +33,15 @@ DEFAULT_BM = 256
 DEFAULT_BV = 1024
 DEFAULT_BK = 512
 NEG_INF = -1e30
+LANES = 128
 
 
 def _logprob_kernel(tgt_ref, h_ref, w_ref, lp_ref, ent_ref,
                     logits_ref, m_ref, l_ref, s_ref, t_ref,
-                    *, n_v, n_k, bv, softcap):
-    i = pl.program_id(0)
+                    *, n_k, bv, vocab, softcap):
     v = pl.program_id(1)
     k = pl.program_id(2)
+    n_v = pl.num_programs(1)
 
     @pl.when((v == 0) & (k == 0))
     def _init_row():
@@ -55,12 +64,11 @@ def _logprob_kernel(tgt_ref, h_ref, w_ref, lp_ref, ent_ref,
         logits = logits_ref[...]                     # [BM, BV]
         if softcap:
             logits = jnp.tanh(logits / softcap) * softcap
-        bm = logits.shape[0]
-        # target gather: ids within this vocab tile
-        tgt = tgt_ref[pl.ds(i * bm, bm)]             # [BM]
-        local = tgt - v * bv
         cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        hit = cols == local[:, None]
+        logits = jnp.where(v * bv + cols < vocab, logits, NEG_INF)
+        # target gather: ids within this vocab tile
+        local = tgt_ref[...] - v * bv                # [BM, 1]
+        hit = cols == local
         t_ref[...] = jnp.maximum(
             t_ref[...],
             jnp.max(jnp.where(hit, logits, NEG_INF), axis=1, keepdims=True))
@@ -82,53 +90,46 @@ def _logprob_kernel(tgt_ref, h_ref, w_ref, lp_ref, ent_ref,
                             jnp.maximum(l_ref[...], 1e-30)).astype(ent_ref.dtype)
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bv", "bk", "softcap",
                                              "interpret"))
 def token_logprob_flat(h, w, targets, *, bm=DEFAULT_BM, bv=DEFAULT_BV,
                        bk=DEFAULT_BK, softcap=0.0, interpret=None):
     """h: [R, d]; w: [d, V]; targets: [R] int32.
-    Returns (logprob [R], entropy [R]) float32."""
+    Returns (logprob [R], entropy [R]) float32. `bv` is rounded up to a
+    multiple of 128 lanes."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     R, d = h.shape
     V = w.shape[1]
+    Vp = _round_up(V, LANES)
     bm = min(bm, max(8, R))
-    bv = min(bv, V)
+    bv = min(_round_up(bv, LANES), Vp)
     bk = min(bk, d)
-    Rp = -(-R // bm) * bm
-    Vp = -(-V // bv) * bv
-    dp = -(-d // bk) * bk
+    Rp = _round_up(R, bm)
+    dp = _round_up(d, bk)
     if Rp != R:
         h = jnp.pad(h, ((0, Rp - R), (0, 0)))
         targets = jnp.pad(targets, (0, Rp - R))
-    if dp != d:
+    if dp != d or Vp != V:
         h = jnp.pad(h, ((0, 0), (0, dp - d)))
-        w = jnp.pad(w, ((0, dp - d), (0, 0)))
-    if Vp != V:
-        # pad vocab with NEG_INF-like columns: zero weights give logit 0,
-        # which would corrupt lse — mask by giving padded cols −∞ via a
-        # large negative bias row trick: instead pad W with zeros and rely
-        # on masking below (cols >= V are never targets; their logit 0 can
-        # distort lse). To stay exact we fold padding into the last tile
-        # mask inside the kernel — cheaper: require V % bv == 0 by choosing
-        # bv that divides V.
-        for cand in (bv, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-            if V % cand == 0:
-                bv = cand
-                break
-        Vp = V
-    n_v = Vp // bv
+        w = jnp.pad(w, ((0, dp - d), (0, Vp - V)))
     n_k = dp // bk
-    grid = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Rp // bm, n_v, n_k),
+    lp, ent = pl.pallas_call(
+        functools.partial(_logprob_kernel, n_k=n_k, bv=bv, vocab=V,
+                          softcap=softcap),
+        grid=(Rp // bm, pl.cdiv(Vp, bv), n_k),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, v, k, t: (i, k)),
-            pl.BlockSpec((bk, bv), lambda i, v, k, t: (k, v)),
+            pl.BlockSpec((bm, 1), lambda i, v, k: (i, 0)),
+            pl.BlockSpec((bm, bk), lambda i, v, k: (i, k)),
+            pl.BlockSpec((bk, bv), lambda i, v, k: (k, v)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, 1), lambda i, v, k, t: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i, v, k, t: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, v, k: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, v, k: (i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, bv), jnp.float32),
@@ -137,13 +138,8 @@ def token_logprob_flat(h, w, targets, *, bm=DEFAULT_BM, bv=DEFAULT_BV,
             pltpu.VMEM((bm, 1), jnp.float32),
             pltpu.VMEM((bm, 1), jnp.float32),
         ],
-    )
-    lp, ent = pl.pallas_call(
-        functools.partial(_logprob_kernel, n_v=n_v, n_k=n_k, bv=bv,
-                          softcap=softcap),
-        grid_spec=grid,
         out_shape=[jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Rp, 1), jnp.float32)],
         interpret=interpret,
-    )(targets.astype(jnp.int32), h, w)
+    )(targets.astype(jnp.int32).reshape(Rp, 1), h, w)
     return lp[:R, 0], ent[:R, 0]

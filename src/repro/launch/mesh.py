@@ -5,42 +5,23 @@ all-reduce on scattered shards; DCN-friendly).
 
 Defined as functions, never module-level constants: importing this module
 must not touch jax device state (the dry-run pins a 512-device host platform
-before any jax import).
-
-Version compat: `jax.sharding.AxisType` (and the `axis_types=` kwarg on
-`jax.make_mesh`/`AbstractMesh`) only exists in newer JAX; on the pinned
-0.4.37 every axis is implicitly Auto. `make_mesh`/`make_abstract_mesh`
-feature-detect and fall back, so callers never touch `AxisType` directly."""
+before any jax import). Every axis is explicitly Auto."""
 from __future__ import annotations
 
 import jax
-
-
-def _auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on JAX >= 0.5, else None (0.4.x is always Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with explicit Auto axis_types where supported."""
-    auto = _auto_axis_types(len(axes))
-    if auto is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
+    """`jax.make_mesh` with explicit Auto axis_types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-less AbstractMesh across the 0.4.x / 0.5.x signature change:
-    new JAX takes (axis_sizes, axis_names, axis_types=...), 0.4.37 takes a
-    ((name, size), ...) shape tuple."""
-    auto = _auto_axis_types(len(axes))
-    if auto is None:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """Device-less AbstractMesh with explicit Auto axis_types."""
     return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
-                                     axis_types=auto)
+                                     axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, kind: str = "train"):

@@ -1,16 +1,19 @@
 """Multi-LoRA serving service CLI (the rollout engine in serve-only mode).
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --tenants 4
+
+The arch's registry config is served unchanged; --reduced swaps in its
+tiny fp32 twin with the tokenizer's vocabulary.
 """
 import argparse
-import dataclasses
 import random
 
 import jax
 
-from repro.configs import REGISTRY, reduced
 from repro.data import tokenizer as tok
 from repro.envs.tasks import make_env
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.train import model_config
 from repro.lora.adapters import init_lora
 from repro.models import init_params
 from repro.rollout.engine import RolloutEngine, RolloutRequest
@@ -24,11 +27,12 @@ def main():
                     help="route adapter matmuls through the Pallas SGMV "
                          "kernel (interpret mode on CPU)")
     ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny fp32 config with the tokenizer vocabulary")
     args = ap.parse_args()
 
-    cfg = dataclasses.replace(reduced(REGISTRY[args.arch],
-                                      dtype="float32"),
-                              vocab_size=tok.VOCAB_SIZE)
+    enable_compile_cache()
+    cfg = model_config(args.arch, args.reduced)
     params = init_params(jax.random.PRNGKey(0), cfg)
     adapters = [init_lora(jax.random.PRNGKey(100 + t), cfg)
                 for t in range(args.tenants)]

@@ -171,8 +171,10 @@ def attention_prefill_chunk(q, k, v, cfg: ModelConfig, *, q_start: int,
 
 def _static_window(window) -> bool:
     """True when `window` is a python int (the Pallas decode kernels take
-    it as a static arg; gemma2's per-layer window array is TRACED through
-    the layer scan and falls back to the einsum path)."""
+    it as a static arg). gemma2 passes its per-layer window as an array
+    TRACED through the layer scan; that one case runs the einsum path
+    even under ``use_kernel=True`` — every other decode shape runs the
+    kernel."""
     return isinstance(window, int)
 
 
@@ -185,13 +187,13 @@ def attention_decode(q, cache_k, cache_v, pos, cfg: ModelConfig, *, window=0,
     caches costs rep× the decode step's HBM traffic (measured 10GB/step at
     granite decode_32k — EXPERIMENTS.md §Perf iter A2). Under
     ``use_kernel=True`` the Pallas ``gqa_decode`` flash-decode kernel runs
-    instead (when the window is static and the cache length tiles evenly);
-    the einsum path is retained as the oracle it is parity-tested against.
+    instead for every cache length (its KV block is a divisor of Smax);
+    only gemma2's traced per-layer window stays on the einsum path, which
+    is retained as the oracle the kernel is parity-tested against.
     """
     B, H, hd = q.shape
     Smax = cache_k.shape[1]
-    if (use_kernel and _static_window(window)
-            and (Smax <= 512 or Smax % 512 == 0)):
+    if use_kernel and _static_window(window):
         from repro.kernels.gqa_decode import gqa_decode
         return gqa_decode(q, cache_k, cache_v, pos,
                           softcap=float(cfg.attn_softcap or 0.0),
@@ -228,7 +230,8 @@ def attention_decode_paged(q, kp, vp, tbl, pos, cfg: ModelConfig, *,
     output == dense output exactly. Under ``use_kernel=True`` the Pallas
     ``paged_gqa_decode`` kernel reads the pages in place via a
     scalar-prefetched block table instead (no contiguous gather ever
-    materializes)."""
+    materializes); gemma2's traced per-layer window is the one case that
+    keeps the oracle path."""
     if use_kernel and _static_window(window):
         from repro.kernels.paged_decode import paged_gqa_decode
         return paged_gqa_decode(q, kp, vp, tbl, pos,

@@ -48,11 +48,7 @@ def mesh_context(mesh: Optional[Mesh], rules=None):
                          else DEFAULT_RULES)
     try:
         if mesh is not None:
-            # jax.sharding.set_mesh only exists on newer JAX; 0.4.x spells
-            # the same thing as the Mesh context manager.
-            set_mesh = getattr(jax.sharding, "set_mesh", None)
-            ctx = set_mesh(mesh) if set_mesh is not None else mesh
-            with ctx:
+            with jax.sharding.set_mesh(mesh):
                 yield
         else:
             yield

@@ -471,8 +471,7 @@ def _force_calls(monkeypatch, call_at=2):
     """Deterministic forced-CALL pattern (the bench_async_train idiom):
     every row samples CALL at token counter `call_at` (a plain token for
     non-agentic tenants) and EOS is remapped away. Tool calls must not
-    depend on what the randomly-initialized model happens to sample —
-    prompt datagens are seeded via process-salted hash()."""
+    depend on what the randomly-initialized model happens to sample."""
     import jax.numpy as jnp
 
     import repro.rollout.engine as eng_mod

@@ -136,3 +136,13 @@ def test_metrics_util_and_idle():
     idle = rec.idle_pct()
     # rollout busy half the span (40 dev-s of 80), train busy 10 of 100 total
     assert abs(idle - 100 * (1 - 50.0 / 100.0)) < 1e-6
+
+
+def test_task_seed_is_stable_across_processes():
+    """Per-tenant LoRA keys and prompt datagens derive from crc32, not the
+    process-salted hash(): the same (seed, task id) gives the same value
+    in every process, so two runs see the same adapters and prompts."""
+    from repro.core.runtime import task_seed
+    assert task_seed(0, "gsm8k-0") == 165592061
+    assert task_seed(1, "gsm8k-0") == 514174910
+    assert task_seed(0, "hopsearch-1") == 1761844316

@@ -7,9 +7,10 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.sgmv import sgmv
-from repro.kernels.gqa_decode import gqa_decode
+from repro.kernels.gqa_decode import block_len, gqa_decode
 from repro.kernels.paged_decode import paged_gqa_decode
 from repro.kernels.token_logprob import token_logprob_flat
+from repro.rl.grpo import token_logprobs_chunked
 
 KEY = jax.random.PRNGKey(0)
 
@@ -46,7 +47,7 @@ def test_sgmv_empty_group():
 
 @pytest.mark.parametrize("B,H,KVH,hd,S", [
     (2, 4, 2, 16, 64), (3, 8, 2, 32, 128), (2, 4, 4, 16, 64),
-    (1, 12, 2, 16, 96), (2, 16, 8, 64, 256),
+    (1, 12, 2, 16, 96), (2, 16, 8, 64, 256), (2, 16, 8, 128, 96),
 ])
 @pytest.mark.parametrize("softcap,window", [(0.0, 0), (50.0, 0), (0.0, 24)])
 def test_gqa_decode_sweep(B, H, KVH, hd, S, softcap, window):
@@ -88,7 +89,7 @@ def _paged_cache(key, B, n_pg, page, KVH, hd, dtype):
 
 @pytest.mark.parametrize("B,H,KVH,hd,n_pg,page", [
     (2, 4, 2, 16, 4, 16), (3, 8, 2, 32, 2, 64), (2, 4, 4, 16, 8, 8),
-    (1, 12, 2, 16, 3, 32), (2, 16, 8, 64, 2, 128),
+    (1, 12, 2, 16, 3, 32), (2, 16, 8, 64, 2, 128), (2, 16, 8, 128, 3, 16),
 ])
 @pytest.mark.parametrize("softcap,window", [(0.0, 0), (50.0, 0), (0.0, 24)])
 def test_paged_gqa_decode_sweep(B, H, KVH, hd, n_pg, page, softcap, window):
@@ -207,3 +208,66 @@ def test_ops_wrappers_shapes():
     want_lp, _ = ref.token_logprob_ref(h, w, t)
     np.testing.assert_allclose(np.asarray(lp), np.asarray(want_lp),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("S,bs,want", [(96, 512, 96), (1024, 512, 512),
+                                       (600, 512, 200), (36, 512, 36),
+                                       (13, 8, 1)])
+def test_gqa_decode_block_len(S, bs, want):
+    """Every cache length gets a dividing KV block (8-aligned where one
+    exists), so use_kernel never needs the einsum for a static window."""
+    assert block_len(S, bs) == want
+
+
+def test_gqa_decode_untiled_cache_length():
+    """A cache longer than the default block that the block does not
+    divide runs through the kernel (block 200 for S=600)."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (2, 8, 32), jnp.float32)
+    ck = jax.random.normal(ks[1], (2, 600, 4, 32), jnp.float32)
+    cv = jax.random.normal(ks[2], (2, 600, 4, 32), jnp.float32)
+    pos = jnp.array([311, 600])
+    np.testing.assert_allclose(np.asarray(gqa_decode(q, ck, cv, pos)),
+                               np.asarray(ref.gqa_decode_ref(q, ck, cv, pos)),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("V", [49155, 151936])
+def test_token_logprob_padded_vocab(V):
+    """Real vocabularies at the default tiles: 49155 is padded to 128
+    lanes, and neither divides the 1024-wide tile, so the last tile is
+    partial; the masked columns must not reach lse or entropy."""
+    ks = jax.random.split(KEY, 3)
+    h = jax.random.normal(ks[0], (16, 64), jnp.float32)
+    w = jax.random.normal(ks[1], (64, V), jnp.float32) * 0.3
+    t = jax.random.randint(ks[2], (16,), V - 200, V)    # tail-tile targets
+    lp, ent = token_logprob_flat(h, w, t)
+    want_lp, want_ent = ref.token_logprob_ref(h, w, t)
+    np.testing.assert_allclose(np.asarray(lp), np.asarray(want_lp),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(ent), np.asarray(want_ent),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("softcap", [0.0, 30.0])
+def test_token_logprob_grad_matches_chunked(softcap):
+    """The kernel's custom VJP gives the gradients of the chunked jnp
+    path (what use_kernel=True trains with)."""
+    ks = jax.random.split(KEY, 4)
+    B, S, d, V = 2, 12, 32, 300
+    h = jax.random.normal(ks[0], (B, S, d), jnp.float32)
+    w = jax.random.normal(ks[1], (d, V), jnp.float32) * 0.3
+    t = jax.random.randint(ks[2], (B, S), 0, V)
+    wt = jax.random.normal(ks[3], (B, S), jnp.float32)
+
+    def loss(fn):
+        def f(h, w):
+            lp, ent = fn(h, w, t, softcap)
+            return jnp.sum(lp * wt) + 0.1 * jnp.sum(ent)
+        return f
+
+    got = jax.grad(loss(ops.token_logprob), argnums=(0, 1))(h, w)
+    want = jax.grad(loss(token_logprobs_chunked), argnums=(0, 1))(h, w)
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
+                                   rtol=1e-5, atol=1e-6)
