@@ -105,8 +105,10 @@ from repro.configs import ModelConfig
 from repro.envs.tasks import make_env
 from repro.lora.adapters import init_lora
 from repro.lora.multilora import AdapterResidency
-from repro.rollout.engine import (ContinuousRolloutEngine, RolloutEngine,
-                                  RolloutRequest, to_trajectory_batch)
+from repro.obs.phase import gc_spans, phase
+from repro.rollout.engine import (STEP_TRACK, ContinuousRolloutEngine,
+                                  RolloutEngine, RolloutRequest,
+                                  to_trajectory_batch)
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import TrainConfig, init_opt_state, make_train_step
 from .admission import AdmissionConfig, AdmissionController
@@ -115,6 +117,9 @@ from .manager import MultiTaskManager, TaskSpec
 from .metrics import MetricsRecorder
 from .supervisor import (ABANDONED, CLOSED, HALF_OPEN, OPEN,  # noqa: F401
                          TenantBreaker, join_or_raise)
+
+
+TRAIN_TRACK = ("train", "trainer")     # tracer track of the trainer's phases
 
 
 def task_seed(seed: int, task_id: str) -> int:
@@ -593,12 +598,6 @@ class MARLaaSRuntime:
             self.rec.record("rollout", "decode", name,
                             self._seg_t0, now,
                             self.rcfg.rollout_pool_devices)
-            if self.tracer is not None:
-                # the fused decode stream as one Perfetto track: each slice
-                # is a contiguous occupant-set run (same data the recorder
-                # books as decode busy time)
-                self.tracer.span(("rollout", "decode"), name,
-                                 self._seg_t0, now)
         self._seg_t0 = now
         self._seg_tasks = frozenset()
 
@@ -734,43 +733,49 @@ class MARLaaSRuntime:
         last_queue_sample = None
         last_env_sample = None
         last_page_sample = None
+        tr = self.tracer
         while not self._stop.is_set():
-            self._execute_preemptions()
-            fed = self._feed_continuous()
+            with phase("runtime.preempt", tr, STEP_TRACK):
+                self._execute_preemptions()
+            with phase("runtime.feed", tr, STEP_TRACK):
+                fed = self._feed_continuous()
             progressed = eng.step()
-            now = time.monotonic()
-            occ, cap = eng.occupancy()
-            # step-function timeline: sample only on occupancy change (idle
-            # spins would otherwise append hundreds of samples per second)
-            if (occ, cap) != last_slot_sample:
-                self.rec.record_slot_sample(now, occ, cap)
-                last_slot_sample = (occ, cap)
-            qd = eng.queue_depths()
-            if qd != last_queue_sample:
-                self.rec.record_queue_sample(now, *qd)
-                last_queue_sample = qd
-            if self.rcfg.env_stage:
-                ed = eng.env_depths()
-                if ed != last_env_sample:
-                    self.rec.record_env_sample(now, *ed)
-                    last_env_sample = ed
-            if self.rcfg.paged_kv:
-                ps = eng.page_stats()
-                key = (ps["kv_pages_used"], round(ps["kv_page_frag"], 3))
-                if key != last_page_sample:
-                    self.rec.record_page_sample(
-                        now, int(ps["kv_pages_used"]),
-                        int(ps["kv_pages_total"]), ps["kv_page_frag"])
-                    last_page_sample = key
-            # decode timeline: one interval per contiguous occupant-set run,
-            # task_id joined with "+" (fused multi-tenant decode)
-            tasks_now = eng.occupant_tasks()
-            if tasks_now != self._seg_tasks:
-                self._flush_decode_segment(now)
-                self._seg_tasks = tasks_now
-            for comp in eng.drain_completions():
-                if self._handle_completion(comp, rounds):
-                    progressed = True
+            with phase("runtime.samples", tr, STEP_TRACK) as ph:
+                now = ph.t0
+                occ, cap = eng.occupancy()
+                # step-function timeline: sample only on occupancy change
+                # (idle spins would otherwise append hundreds of samples
+                # per second)
+                if (occ, cap) != last_slot_sample:
+                    self.rec.record_slot_sample(now, occ, cap)
+                    last_slot_sample = (occ, cap)
+                qd = eng.queue_depths()
+                if qd != last_queue_sample:
+                    self.rec.record_queue_sample(now, *qd)
+                    last_queue_sample = qd
+                if self.rcfg.env_stage:
+                    ed = eng.env_depths()
+                    if ed != last_env_sample:
+                        self.rec.record_env_sample(now, *ed)
+                        last_env_sample = ed
+                if self.rcfg.paged_kv:
+                    ps = eng.page_stats()
+                    key = (ps["kv_pages_used"], round(ps["kv_page_frag"], 3))
+                    if key != last_page_sample:
+                        self.rec.record_page_sample(
+                            now, int(ps["kv_pages_used"]),
+                            int(ps["kv_pages_total"]), ps["kv_page_frag"])
+                        last_page_sample = key
+                # decode timeline: one interval per contiguous occupant-set
+                # run, task_id joined with "+" (fused multi-tenant decode)
+                tasks_now = eng.occupant_tasks()
+                if tasks_now != self._seg_tasks:
+                    self._flush_decode_segment(now)
+                    self._seg_tasks = tasks_now
+            with phase("runtime.completions", tr, STEP_TRACK):
+                for comp in eng.drain_completions():
+                    if self._handle_completion(comp, rounds):
+                        progressed = True
             if self.breaker is not None:
                 self._poll_breaker(rounds)
             if not progressed and not fed:
@@ -854,40 +859,40 @@ class MARLaaSRuntime:
         st = self.mgr.state(tb.task_id)
         tc = self._tc(st.spec)
         step_fn = self._train_step_for(st.spec)
-        batch = {
-            "tokens": jnp.asarray(tb.tokens),
-            "prompt_lens": jnp.asarray(tb.prompt_lens),
-            "total_lens": jnp.asarray(tb.total_lens),
-            "rewards": jnp.asarray(tb.rewards),
-        }
-        if "loss_mask" in tb.meta:
-            batch["loss_mask"] = jnp.asarray(tb.meta["loss_mask"])
-        if tc.is_cap > 0 and tb.behavior_logprobs is not None:
-            # decoupled-PPO correction: the loss reweights by
-            # min(exp(old_lp - behavior_lp), is_cap) — behaviour logprobs
-            # were recorded at sample time under the generating version
-            batch["behavior_logprobs"] = jnp.asarray(tb.behavior_logprobs)
+        tracer = self.tracer
+        with phase("trainer.assemble", tracer, TRAIN_TRACK):
+            batch = {
+                "tokens": jnp.asarray(tb.tokens),
+                "prompt_lens": jnp.asarray(tb.prompt_lens),
+                "total_lens": jnp.asarray(tb.total_lens),
+                "rewards": jnp.asarray(tb.rewards),
+            }
+            if "loss_mask" in tb.meta:
+                batch["loss_mask"] = jnp.asarray(tb.meta["loss_mask"])
+            if tc.is_cap > 0 and tb.behavior_logprobs is not None:
+                # decoupled-PPO correction: the loss reweights by
+                # min(exp(old_lp - behavior_lp), is_cap) — behaviour
+                # logprobs were recorded at sample time under the
+                # generating version
+                batch["behavior_logprobs"] = jnp.asarray(
+                    tb.behavior_logprobs)
         trace_ids = (tb.meta.get("trace_ids", ())
-                     if self.tracer is not None else ())
-        t0 = time.monotonic()
-        if self.tracer is not None:
+                     if tracer is not None else ())
+        with phase("trainer.step", tracer, TRAIN_TRACK) as ph:
             for tr in trace_ids:
-                self.tracer.mark(tr, "train", t0)
-        new_adapters, new_opt, metrics = step_fn(self.base_params, st.adapters,
-                                                 st.opt_state, batch)
-        jax.block_until_ready(jax.tree.leaves(new_adapters)[0])
-        t1 = time.monotonic()
-        self.rec.record("train", "train", tb.task_id, t0, t1,
+                tracer.mark(tr, "train", ph.t0)
+            new_adapters, new_opt, metrics = step_fn(
+                self.base_params, st.adapters, st.opt_state, batch)
+            jax.block_until_ready(jax.tree.leaves(new_adapters)[0])
+        self.rec.record("train", "train", tb.task_id, ph.t0, ph.t1,
                         self.rcfg.train_pool_devices)
-        self.mgr.commit(tb.task_id, new_adapters, new_opt, trained_version,
-                        reward_mean=float(np.mean(tb.rewards)),
-                        loss=float(metrics["loss"]))
-        if self.tracer is not None:
-            t_commit = self.tracer.now()
-            self.tracer.span(("train", "trainer"), tb.task_id, t0, t_commit,
-                             flow_in=0, flow_out=0)
-            for tr in trace_ids:
-                self.tracer.mark(tr, "committed", t_commit)
+        with phase("trainer.commit", tracer, TRAIN_TRACK) as ph:
+            self.mgr.commit(tb.task_id, new_adapters, new_opt,
+                            trained_version,
+                            reward_mean=float(np.mean(tb.rewards)),
+                            loss=float(metrics["loss"]))
+        for tr in trace_ids:
+            tracer.mark(tr, "committed", ph.t1)
         self.mgr.rows_trained += tb.num_rows
         self.rec.record_train_backlog(time.monotonic(),
                                       self.mgr.dispatchable_rows())
@@ -914,10 +919,10 @@ class MARLaaSRuntime:
                 self._train_loop_async()
                 return
             while not self._stop.is_set():
-                t0 = time.monotonic()
-                tb = self.mgr.pop_batch(timeout=0.05)
+                with phase("trainer.pop", self.tracer, TRAIN_TRACK) as ph:
+                    tb = self.mgr.pop_batch(timeout=0.05)
                 if tb is None:
-                    self.rec.record_trainer_wait(t0, time.monotonic())
+                    self.rec.record_trainer_wait(ph.t0, ph.t1)
                     if self.mgr.all_done():
                         return
                     continue
@@ -935,10 +940,10 @@ class MARLaaSRuntime:
         between commits is bounded by decode throughput, not by the
         slowest row of a round."""
         while not self._stop.is_set():
-            t0 = time.monotonic()
-            item = self.mgr.pop_episodes(timeout=0.05)
+            with phase("trainer.pop", self.tracer, TRAIN_TRACK) as ph:
+                item = self.mgr.pop_episodes(timeout=0.05)
             if item is None:
-                self.rec.record_trainer_wait(t0, time.monotonic())
+                self.rec.record_trainer_wait(ph.t0, ph.t1)
                 if self.mgr.all_done():
                     return
                 continue
@@ -953,8 +958,9 @@ class MARLaaSRuntime:
             oldest = min(g.version for g in groups)
             newest = max(g.version for g in groups)
             spec = self.mgr.spec_for(tid)
-            tb = to_trajectory_batch(rows, tid, newest, spec.group_size,
-                                     pad_to=self.rcfg.max_len)
+            with phase("trainer.assemble", self.tracer, TRAIN_TRACK):
+                tb = to_trajectory_batch(rows, tid, newest, spec.group_size,
+                                         pad_to=self.rcfg.max_len)
             if self.tracer is not None:
                 tb.meta["trace_ids"] = self._trace_ids_of(rows)
             if self.mgr.version_of(tid) - oldest > 0:
@@ -1060,7 +1066,16 @@ class MARLaaSRuntime:
 
     # -- drivers ----------------------------------------------------------------
     def run(self, timeout_s: float = 600.0):
-        """Run to completion under the configured policy."""
+        """Run to completion under the configured policy. While it runs,
+        every garbage collection is a ``marlaas.python.gc`` span on the
+        profiler's trace."""
+        gcs = gc_spans().install()
+        try:
+            self._run(timeout_s)
+        finally:
+            gcs.remove()
+
+    def _run(self, timeout_s: float):
         for tid in self._pending_by_priority():
             spec = self.mgr.spec_for(tid)
             wl_prompt = 32

@@ -153,6 +153,7 @@ from repro.envs.base import CancelToken, Env, ToolError, call_session
 from repro.lora.adapters import batched_ctx, init_stacked_buffer, stack_adapters
 from repro.models import (decode_step, forward_prefill_chunk, forward_seq,
                           init_cache, init_paged_cache, lm_logits)
+from repro.obs.phase import phase
 from repro.rl.types import RolloutCompletion, TrajectoryBatch
 from repro.rollout.env_stage import EnvStage
 from repro.rollout.kvcache import (KVSnapshot, PagePool, PrefixIndex,
@@ -160,6 +161,9 @@ from repro.rollout.kvcache import (KVSnapshot, PagePool, PrefixIndex,
 from repro.rollout.prefill import (PrefillKernels, PrefillWorker, ReadyRow,
                                    _bucket_len, _sample_rows, effective_chunk)
 from repro.rollout.scheduler import LengthPredictor, SlotScheduler
+
+STEP_TRACK = ("rollout", "step")    # tracer track of the decode thread's
+                                    # per-step phases
 
 
 @dataclass
@@ -184,9 +188,10 @@ class RolloutRequest:
 class RolloutStats:
     decode_steps: int = 0
     prefill_tokens: int = 0
-    decode_seconds: float = 0.0     # decode-stage device time ONLY (the
-                                    # per-stage split is load-bearing for the
-                                    # Fig-5 utilization metrics)
+    decode_seconds: float = 0.0     # host time from each decode dispatch
+                                    # to its token read (device step plus
+                                    # transfer; the host's own time between
+                                    # steps is host_gap_seconds)
     prefill_seconds: float = 0.0    # prefill-stage device time (fused refill
                                     # OR async prefill-worker calls)
     env_wait_seconds: float = 0.0
@@ -247,6 +252,23 @@ class RolloutStats:
                                    # frozen on a tool wait — the slot dead
                                    # weight env_stage drives to 0 by
                                    # construction
+    # host-side waits, each booked where its work happens
+    host_gap_seconds: float = 0.0  # Σ from one decode step's token read to
+                                   # the next decode dispatch, over pairs of
+                                   # consecutive steps that both decoded:
+                                   # time the decode stream gives the
+                                   # device nothing
+    host_gaps: int = 0
+    queue_wait_seconds: float = 0.0    # Σ from a row's push into the
+                                       # scheduler queue to the pop that
+                                       # hands it to an install path
+                                       # (prefill worker, fused refill,
+                                       # radix fill, restore)
+    queue_pops: int = 0
+    resume_wait_seconds: float = 0.0   # Σ from a tool response's arrival
+                                       # to its row's return into a decode
+                                       # slot
+    resumes_installed: int = 0
     env_wait_by_task: Dict[str, float] = field(default_factory=dict)
                                    # per-tenant env-interaction wait seconds
 
@@ -635,7 +657,7 @@ class _Row:
                  "forced", "status", "forced_q", "finish_reason", "key",
                  "submit_index", "meta", "submitted_at", "started_at",
                  "replays", "session", "turns", "snap", "dev_pages",
-                 "dev_pos", "tool_retries")
+                 "dev_pos", "tool_retries", "resumed_at")
 
     def __init__(self, req: RolloutRequest, key, submit_index: int,
                  meta=None, submitted_at: float = 0.0):
@@ -667,6 +689,8 @@ class _Row:
                                       # (prefix cache: zero-copy park; the
                                       # row owns one refcount per page)
         self.dev_pos = 0              # cache entries those pages hold
+        self.resumed_at = None        # arrival of the tool response this
+                                      # row waits to resume with
 
     def turn_limit(self) -> int:
         """Effective tool-turn budget (0 = unlimited)."""
@@ -1067,6 +1091,8 @@ class ContinuousRolloutEngine:
         self._tracer = tracer
         self._slot_tr_t0 = [0.0] * max_slots    # residency span starts
         self._slot_tr_flow = [0] * max_slots    # incoming hand-off arrows
+        self._t_read: Optional[float] = None    # token read of the last
+                                                # step, while steps decode
         self._master = jax.random.PRNGKey(seed)
         self._rng = np.random.RandomState(seed + 7919)
         self._own_pool = tool_executor is None
@@ -1227,16 +1253,31 @@ class ContinuousRolloutEngine:
             self._sched.push(row, self.stats.refills)
         return row.submit_index
 
-    # -- episode tracing helpers (all no-ops when tracer is None) ---------
+    # -- wait counters ------------------------------------------------------
+    def _book_queue_wait(self, pushed_at: float):  # held: _stage_lock
+        """A row popped off the scheduler queue goes to an install path:
+        book the time since its push (prefill workers book here too, hence
+        the lock)."""
+        self.stats.queue_wait_seconds += max(0.0, time.monotonic()
+                                             - pushed_at)
+        self.stats.queue_pops += 1
+
+    # -- episode tracing helpers (no-ops when tracer is None) -------------
     def _trace_of(self, row: _Row):
         m = row.meta
         return m.get("trace_id") if isinstance(m, dict) else None
 
-    def _tr_install(self, slot: int, row: _Row, t_now: float,
-                    t_pre: float = None, pre_state: str = None):
-        """Row entered a decode slot: open its residency span, consume any
-        pending hand-off arrow (env resume / preempt reinstall), and mark
-        the lifecycle transition(s)."""
+    def _installed(self, slot: int, row: _Row, t_now: float,
+                   t_pre: float = None, pre_state: str = None):
+        """Row entered a decode slot: book a resumed row's wait since its
+        tool response arrived; with a tracer, open its residency span,
+        consume any pending hand-off arrow (env resume / preempt
+        reinstall), and mark the lifecycle transition(s)."""
+        if row.resumed_at is not None:
+            self.stats.resume_wait_seconds += max(0.0,
+                                                  t_now - row.resumed_at)
+            self.stats.resumes_installed += 1
+            row.resumed_at = None
         tr = self._tracer
         if tr is None:
             return
@@ -1687,6 +1728,9 @@ class ContinuousRolloutEngine:
                 row = self._sched.pop_if(
                     self.stats.refills,
                     lambda r: r.snap is not None or r.dev_pages is not None)
+                pushed_at = self._sched.last_pushed_at
+                if row is not None and row.dev_pages is not None:
+                    self._book_queue_wait(pushed_at)
             if row is None:
                 break
             if row.dev_pages is not None:
@@ -1727,7 +1771,7 @@ class ContinuousRolloutEngine:
                 now = time.monotonic()
                 self._rows[slot] = row
                 self._prompts[slot] = list(row.req.prompt)
-                self._tr_install(slot, row, now, t0, "restore")
+                self._installed(slot, row, now, t0, "restore")
                 # ownership transfer back: slot adopts the row's refcounts
                 self._assign_slot_pages(slot, row.dev_pages, row.dev_pos)
                 self._dev_parked.remove(row)
@@ -1750,8 +1794,10 @@ class ContinuousRolloutEngine:
                     self._finish_capacity(row)      # can never fit
                     continue
                 with self._stage_lock:              # pool pressure: retry
-                    self._sched.push(row, self.stats.refills)
+                    self._sched.push(row, self.stats.refills, pushed_at)
                 break
+            with self._stage_lock:
+                self._book_queue_wait(pushed_at)
             slot = free.pop(0)
             t0 = time.monotonic()
             L_attn = 1 if snap.kpages is None else snap.kpages.shape[0]
@@ -1786,7 +1832,7 @@ class ContinuousRolloutEngine:
             now = time.monotonic()
             self._rows[slot] = row
             self._prompts[slot] = list(row.req.prompt)
-            self._tr_install(slot, row, now, t0, "restore")
+            self._installed(slot, row, now, t0, "restore")
             self._assign_slot_pages(slot, pages, snap.pos)
             self._drop_snap(row)
             self.stats.restores += 1
@@ -2151,12 +2197,13 @@ class ContinuousRolloutEngine:
                 row = self._sched.pop_if(
                     self.stats.refills,
                     lambda r: self._radix_candidate(r) is not None)
+                pushed_at = self._sched.last_pushed_at
             if row is None:
                 break
             plan = self._radix_candidate(row)
             if plan is None:      # index mutated between pop and here
                 with self._stage_lock:
-                    self._sched.push(row, self.stats.refills)
+                    self._sched.push(row, self.stats.refills, pushed_at)
                 break
             shared, start, L = plan
             self._pages.retain(shared)
@@ -2165,8 +2212,10 @@ class ContinuousRolloutEngine:
             if fresh is None:     # pool pressure: retry next step
                 self._pages.release(shared)
                 with self._stage_lock:
-                    self._sched.push(row, self.stats.refills)
+                    self._sched.push(row, self.stats.refills, pushed_at)
                 break
+            with self._stage_lock:
+                self._book_queue_wait(pushed_at)
             slot = free.pop(0)
             t0 = time.monotonic()
             seq = list(row.req.prompt) + row.gen
@@ -2198,7 +2247,7 @@ class ContinuousRolloutEngine:
             installed += 1
             self._rows[slot] = row
             self._prompts[slot] = list(row.req.prompt)
-            self._tr_install(slot, row, now, t0, "prefill")
+            self._installed(slot, row, now, t0, "prefill")
             self._assign_slot_pages(slot, shared + fresh, L)
             self._index_prompt(row, shared + fresh)
             self.stats.prefix_hits += 1
@@ -2277,7 +2326,6 @@ class ContinuousRolloutEngine:
         self._ensure_built()
         if self._stacked is None:
             raise RuntimeError("no adapters installed — call set_adapters()")
-        t0 = time.monotonic()
         incoming: List[Tuple[int, _Row]] = []
         pages_of: List[List[int]] = []
         seen_keys = set()
@@ -2302,6 +2350,7 @@ class ContinuousRolloutEngine:
                 row = self._sched.pop(self.stats.refills, where=where)
                 if row is None:
                     break
+                pushed_at = self._sched.last_pushed_at
                 if self.paged_kv:
                     n_pg = self._row_pages_needed(
                         len(row.req.prompt) + len(row.gen))
@@ -2314,10 +2363,11 @@ class ContinuousRolloutEngine:
                             self._finish_capacity(row)   # can never fit
                             continue
                         # pool pressure: resident rows will free pages
-                        self._sched.push(row, self.stats.refills)
+                        self._sched.push(row, self.stats.refills, pushed_at)
                         pressure = True
                         break
                     pages_of.append(pages)
+                self._book_queue_wait(pushed_at)
                 incoming.append((free.pop(0), row))
                 if radix:
                     seen_keys.add(self._group_key(row))
@@ -2328,6 +2378,72 @@ class ContinuousRolloutEngine:
                 # (outside the lock) so the next step's alloc succeeds
                 self._spill_dev_parked()
             return False
+        with phase("prefill.fused", self._tracer, ("prefill", "fused")) as ph:
+            seqs, fused, fmask, first, lp, flp = self._fused_prefill(
+                incoming, pages_of)
+        t0, now = ph.t0, ph.t1
+        self.stats.refills += 1
+        # stage attribution (pre-existing bug: this was booked as decode
+        # time): the fused refill is PREFILL-stage work, and because it runs
+        # on the decode stream it is also decode-stall time — the quantity
+        # the disaggregated path drives to zero.
+        self.stats.prefill_seconds += now - t0
+        self.stats.decode_stall_seconds += now - t0
+        if self.on_stage is not None:
+            self.on_stage("prefill",
+                          "+".join(sorted({r.req.task_id
+                                           for _, r in incoming})), t0, now)
+        for j, (slot, row) in enumerate(incoming):
+            self._rows[slot] = row
+            self._prompts[slot] = list(row.req.prompt)
+            self._installed(slot, row, now, t0, "prefill")
+            if self.paged_kv:
+                self._assign_slot_pages(slot, pages_of[j], len(seqs[j]))
+                self._index_prompt(row, pages_of[j])
+            was_forced = fmask[j] == 1
+            L_replay = len(row.req.prompt) + len(row.gen)
+            if was_forced or fused[j]:            # env-stage resume splice
+                if row.gen:   # the resume re-prefilled prompt+prefix: the
+                    self.stats.replays += 1       # per-turn recomputation
+                    self.stats.replay_tokens += L_replay  # restore kills
+            elif row.gen:                         # preemption replay
+                self.stats.replays += 1
+                self.stats.replay_tokens += L_replay
+            else:                                 # fresh row
+                self.stats.prefills += 1
+                row.started_at = now
+            self.stats.prefill_tokens += len(seqs[j])
+            if fused[j]:
+                Fj = len(row.forced_q)
+                self.stats.fused_forced_tokens += Fj
+                self.stats.tokens_generated += Fj
+                for t in range(Fj):
+                    tk = row.forced_q.pop(0)   # pop BEFORE accept, like the
+                    # step-wise path: accept's budget check reads forced_q
+                    action = row.accept(tk, float(flp[j, t]), 0.0,
+                                        self.max_len)
+                    assert action == "continue", \
+                        "fusion guard admitted a terminating forced block"
+            elif was_forced:
+                row.forced_q.pop(0)
+            self.stats.tokens_generated += 1
+            if not was_forced:
+                self.stats.sampled_tokens += 1
+            action = row.accept(int(first[j]), float(lp[j]),
+                                0.0 if was_forced else 1.0, self.max_len)
+            if action == "call":
+                self._on_call(slot)
+            elif action == "done":
+                self._evict(slot)
+        return True
+
+    def _fused_prefill(self, incoming: List[Tuple[int, "_Row"]],
+                       pages_of: List[List[int]]):
+        """The fused refill's one jitted call over the popped rows: their
+        prefill, KV splice and first tokens. Returns ``(seqs, fused,
+        fmask, first, lp, flp)`` on the host (``flp`` None off the paged
+        path)."""
+        flp = None
         k = len(incoming)
         W = 1                                    # next-pow2 width bucket
         while W < k:
@@ -2400,84 +2516,26 @@ class ContinuousRolloutEngine:
          self._d_row_ids) = state
         first = np.asarray(first)
         lp = np.asarray(lp)
-        now = time.monotonic()
-        self.stats.refills += 1
-        # stage attribution (pre-existing bug: this was booked as decode
-        # time): the fused refill is PREFILL-stage work, and because it runs
-        # on the decode stream it is also decode-stall time — the quantity
-        # the disaggregated path drives to zero.
-        self.stats.prefill_seconds += now - t0
-        self.stats.decode_stall_seconds += now - t0
-        if self.on_stage is not None:
-            self.on_stage("prefill",
-                          "+".join(sorted({r.req.task_id
-                                           for _, r in incoming})), t0, now)
-        if self._tracer is not None:
-            self._tracer.span(("prefill", "fused"),
-                              "+".join(sorted({r.req.task_id
-                                               for _, r in incoming})),
-                              t0, now)
-        for j, (slot, row) in enumerate(incoming):
-            self._rows[slot] = row
-            self._prompts[slot] = list(row.req.prompt)
-            self._tr_install(slot, row, now, t0, "prefill")
-            if self.paged_kv:
-                self._assign_slot_pages(slot, pages_of[j], len(seqs[j]))
-                self._index_prompt(row, pages_of[j])
-            was_forced = fmask[j] == 1
-            L_replay = len(row.req.prompt) + len(row.gen)
-            if was_forced or fused[j]:            # env-stage resume splice
-                if row.gen:   # the resume re-prefilled prompt+prefix: the
-                    self.stats.replays += 1       # per-turn recomputation
-                    self.stats.replay_tokens += L_replay  # restore kills
-            elif row.gen:                         # preemption replay
-                self.stats.replays += 1
-                self.stats.replay_tokens += L_replay
-            else:                                 # fresh row
-                self.stats.prefills += 1
-                row.started_at = now
-            self.stats.prefill_tokens += len(seqs[j])
-            if fused[j]:
-                Fj = len(row.forced_q)
-                self.stats.fused_forced_tokens += Fj
-                self.stats.tokens_generated += Fj
-                for t in range(Fj):
-                    tk = row.forced_q.pop(0)   # pop BEFORE accept, like the
-                    # step-wise path: accept's budget check reads forced_q
-                    action = row.accept(tk, float(flp[j, t]), 0.0,
-                                        self.max_len)
-                    assert action == "continue", \
-                        "fusion guard admitted a terminating forced block"
-            elif was_forced:
-                row.forced_q.pop(0)
-            self.stats.tokens_generated += 1
-            if not was_forced:
-                self.stats.sampled_tokens += 1
-            action = row.accept(int(first[j]), float(lp[j]),
-                                0.0 if was_forced else 1.0, self.max_len)
-            if action == "call":
-                self._on_call(slot)
-            elif action == "done":
-                self._evict(slot)
-        return True
+        return seqs, fused, fmask, first, lp, flp
 
-    def _splice_ready_rows(self) -> bool:
+    def _splice_ready_rows(self) -> str:
         """Decode-side half of the disaggregated split: install rows the
         async prefill stage finished into freed slots with one scatter-only
         jitted call each. No prefill graph runs on the decode stream — the
-        splice is O(cache row copy), so decode never stalls on a prompt."""
+        splice is O(cache row copy), so decode never stalls on a prompt.
+        Returns the installed rows' tenants joined by "+" ("" for none);
+        ``step`` books the splice time from its ``engine.splice`` phase."""
         free = [s for s in range(self.max_slots) if self._rows[s] is None]
         if not free:
-            return False
+            return ""
         ready: List[ReadyRow] = []
         with self._stage_lock:
             while free and self._ready:
                 ready.append(self._ready.popleft())
                 free.pop(0)
         if not ready:
-            return False
+            return ""
         free = [s for s in range(self.max_slots) if self._rows[s] is None]
-        t0 = time.monotonic()
         installed = 0
         for i_rr, rr in enumerate(ready):
             row = rr.row
@@ -2522,7 +2580,7 @@ class ContinuousRolloutEngine:
             installed += 1
             self._rows[slot] = row
             self._prompts[slot] = list(row.req.prompt)
-            self._tr_install(slot, row, now)
+            self._installed(slot, row, now)
             if self.paged_kv:
                 self._assign_slot_pages(slot, pages, rr.seq_len)
                 self._index_prompt(row, pages)
@@ -2564,19 +2622,9 @@ class ContinuousRolloutEngine:
             elif action == "done":
                 self._evict(slot)
         if installed == 0:
-            return False
-        now = time.monotonic()
+            return ""
         self.stats.refills += 1        # one refill event (starvation aging)
-        self.stats.splice_seconds += now - t0
-        if self.on_stage is not None:
-            self.on_stage("splice",
-                          "+".join(sorted({rr.row.req.task_id
-                                           for rr in ready})), t0, now)
-        if self._tracer is not None:
-            self._tracer.span(("rollout", "splice"),
-                              "+".join(sorted({rr.row.req.task_id
-                                               for rr in ready})), t0, now)
-        return True
+        return "+".join(sorted({rr.row.req.task_id for rr in ready}))
 
     def _on_call(self, slot: int):
         """Route a freshly sampled CALL: park the row in the env stage
@@ -2678,6 +2726,7 @@ class ContinuousRolloutEngine:
                     row.meta["_flow_in"] = fid
             row.forced_q = [tok.RESP] + list(job.response) + [tok.ENDRESP]
             row.status = "active"
+            row.resumed_at = job.resolved_at
             self.stats.resumes += 1
             with self._stage_lock:
                 self._sched.push(row, self.stats.refills)
@@ -2689,51 +2738,62 @@ class ContinuousRolloutEngine:
         """One engine iteration: resolve tools, fill freed slots (fused
         refill, or splice of async-prefilled rows in disaggregated mode),
         one decode step over the pool, evict finished rows. Returns True if
-        any device work happened (refill/splice or decode)."""
-        now = time.monotonic()
+        any device work happened (refill/splice or decode).
+
+        Each part runs in a named phase (``repro.obs.phase``: a
+        ``marlaas.engine.*`` span on the profiler's trace, and a span on
+        the tracer's ``STEP_TRACK`` when a tracer is attached), in this
+        order: supervise, env_pump, restore, radix_fill, splice (refill on
+        the fused path), pages, masks, dispatch, wait, accept."""
+        tr = self._tracer
         progressed = False
         # stage supervision: detect dead/wedged workers, recover their
         # stranded work, respawn to complement under backoff (no-op while
         # every pool is at complement — one healthy() call per stage)
-        self.supervisor.tick(now)
-        # env-interaction stage: expire + resume parked rows (env_stage
-        # mode); the baseline freeze-in-slot path resolves futures below
-        if self._env is not None:
-            self._pump_env_stage()
-        # resolve / time out pending tool calls (freeze-in-slot baseline)
-        for slot in list(self._pending):
-            fut = self._pending[slot]
-            row = self._rows[slot]
-            if fut.done():
-                resp = fut.result()
-                t0w = self._pending_t0[slot]
-                tid = row.req.task_id
-                self.stats.add_env_wait(tid, now - t0w)
-                if self.on_stage is not None:
-                    self.on_stage("env", tid, t0w, now)
-                if self._tracer is not None:
-                    trace = self._trace_of(row)
-                    self._tracer.span(("env", "pool"), tid, t0w, now,
-                                      trace=trace)
-                    self._tracer.mark(trace, "decode", now)
-                row.forced_q = [tok.RESP] + list(resp) + [tok.ENDRESP]
-                row.status = "active"
-                del self._pending[slot], self._pending_t0[slot]
-            elif now - self._pending_t0[slot] > self.tool_timeout_s:
-                row.status, row.finish_reason = "done", "tool_timeout"
-                self._evict(slot)
+        with phase("engine.supervise", tr, STEP_TRACK) as ph:
+            now = ph.t0
+            self.supervisor.tick(now)
+        with phase("engine.env_pump", tr, STEP_TRACK):
+            # env-interaction stage: expire + resume parked rows (env_stage
+            # mode); the baseline freeze-in-slot path resolves futures below
+            if self._env is not None:
+                self._pump_env_stage()
+            # resolve / time out pending tool calls (freeze-in-slot baseline)
+            for slot in list(self._pending):
+                fut = self._pending[slot]
+                row = self._rows[slot]
+                if fut.done():
+                    resp = fut.result()
+                    t0w = self._pending_t0[slot]
+                    tid = row.req.task_id
+                    self.stats.add_env_wait(tid, now - t0w)
+                    if self.on_stage is not None:
+                        self.on_stage("env", tid, t0w, now)
+                    if tr is not None:
+                        trace = self._trace_of(row)
+                        tr.span(("env", "pool"), tid, t0w, now, trace=trace)
+                        tr.mark(trace, "decode", now)
+                    row.forced_q = [tok.RESP] + list(resp) + [tok.ENDRESP]
+                    row.status = "active"
+                    del self._pending[slot], self._pending_t0[slot]
+                elif now - self._pending_t0[slot] > self.tool_timeout_s:
+                    row.status, row.finish_reason = "done", "tool_timeout"
+                    self._evict(slot)
         # snapshot-restore resume (paged engine): queued rows carrying a
         # host snapshot splice their saved pages back on the decode thread
         # — no prefill graph, no replay — before the fill paths run
-        if self.resume_restore and self._restore_rows():
-            progressed = True
+        if self.resume_restore:
+            with phase("engine.restore", tr, STEP_TRACK):
+                if self._restore_rows():
+                    progressed = True
         # radix/GRPO shared installs (prefix cache): rows whose prefix is
         # already in-pool retain it and prefill only their suffix — runs
         # on the decode thread before the private fill paths in BOTH
         # fused and disaggregated modes
-        if self.paged_kv and self._stacked is not None \
-                and self._radix_fill_rows():
-            progressed = True
+        if self.paged_kv and self._stacked is not None:
+            with phase("engine.radix_fill", tr, STEP_TRACK):
+                if self._radix_fill_rows():
+                    progressed = True
         # fill freed slots from the cross-task queue: disaggregated mode
         # splices asynchronously-prefilled rows (decode never runs a prefill
         # graph); fused mode runs the baseline one-call refill
@@ -2750,10 +2810,17 @@ class ContinuousRolloutEngine:
                     self._ensure_stage()  # first start / post-halt only;
                                           # replacements are the
                                           # supervisor's (backoff-gated)
-                if self._splice_ready_rows():
+                with phase("engine.splice", tr, STEP_TRACK) as ph:
+                    spliced = self._splice_ready_rows()
+                if spliced:
                     progressed = True
-        elif self._refill_free_slots():
-            progressed = True
+                    self.stats.splice_seconds += ph.t1 - ph.t0
+                    if self.on_stage is not None:
+                        self.on_stage("splice", spliced, ph.t0, ph.t1)
+        else:
+            with phase("engine.refill", tr, STEP_TRACK):
+                if self._refill_free_slots():
+                    progressed = True
         if self._env is not None:
             # the engine invariant of the disaggregated env stage: a
             # tool-waiting row NEVER occupies a decode slot (it parks)
@@ -2764,64 +2831,75 @@ class ContinuousRolloutEngine:
             # pre-step growth: allocate the page each active row's next
             # K/V write lands in (cache-capacity eviction on exhaustion),
             # then upload the block table if the topology changed
-            self._ensure_decode_pages()
-            if self._tbl_dirty and "tbl" in self._cache:
-                self._cache = dict(self._cache,
-                                   tbl=jnp.asarray(self._tbl_host))
-                self._tbl_dirty = False
-        advance = np.array(
-            [1 if (r is not None and r.status == "active") else 0
-             for r in self._rows], np.int32)
-        if advance.sum() == 0:
+            with phase("engine.pages", tr, STEP_TRACK):
+                self._ensure_decode_pages()
+                if self._tbl_dirty and "tbl" in self._cache:
+                    self._cache = dict(self._cache,
+                                       tbl=jnp.asarray(self._tbl_host))
+                    self._tbl_dirty = False
+        with phase("engine.masks", tr, STEP_TRACK):
+            advance = np.array(
+                [1 if (r is not None and r.status == "active") else 0
+                 for r in self._rows], np.int32)
+            forced = np.zeros((self.max_slots,), np.int32)
+            fmask = np.zeros((self.max_slots,), np.int32)
+            for i, r in enumerate(self._rows):
+                if r is not None and r.status == "active" and r.forced_q:
+                    forced[i] = r.forced_q[0]
+                    fmask[i] = 1
+            # upload the masks only when they changed (steady decode
+            # between evictions re-uses the device copies — zero uploads
+            # per step)
+            sig = advance.tobytes() + forced.tobytes() + fmask.tobytes()
+            if advance.any() and sig != self._mask_sig:
+                self._d_masks = (jnp.asarray(forced), jnp.asarray(fmask),
+                                 jnp.asarray(advance))
+                self._mask_sig = sig
+        if not advance.any():
+            self._t_read = None     # no decode: the next one opens no gap
             return progressed
-        forced = np.zeros((self.max_slots,), np.int32)
-        fmask = np.zeros((self.max_slots,), np.int32)
-        for i, r in enumerate(self._rows):
-            if r is not None and r.status == "active" and r.forced_q:
-                forced[i] = r.forced_q[0]
-                fmask[i] = 1
-        # upload the masks only when they changed (steady decode between
-        # evictions re-uses the device copies — zero uploads per step)
-        sig = advance.tobytes() + forced.tobytes() + fmask.tobytes()
-        if sig != self._mask_sig:
-            self._d_masks = (jnp.asarray(forced), jnp.asarray(fmask),
-                             jnp.asarray(advance))
-            self._mask_sig = sig
         d_forced, d_fmask, d_advance = self._d_masks
-        t0 = time.monotonic()
-        nxt, lp, self._cache, self._d_counters = self._step_fn(
-            self.base_params, self._stacked, self._d_row_ids, self._d_cur,
-            self._cache, self._d_keys, self._d_counters, self._d_temps,
-            d_forced, d_fmask, d_advance)
+        with phase("engine.dispatch", tr, STEP_TRACK) as ph:
+            nxt, lp, self._cache, self._d_counters = self._step_fn(
+                self.base_params, self._stacked, self._d_row_ids,
+                self._d_cur, self._cache, self._d_keys, self._d_counters,
+                self._d_temps, d_forced, d_fmask, d_advance)
         self._d_cur = nxt
-        nxt = np.asarray(nxt)
-        lp = np.asarray(lp)
-        self.stats.decode_seconds += time.monotonic() - t0
-        self.stats.decode_steps += 1
-        self.stats.occupied_row_steps += int(advance.sum())
-        self.stats.capacity_row_steps += self.max_slots
-        # slot dead weight of the freeze-in-slot baseline: resident rows
-        # that spent this decode step waiting on a tool (0 by construction
-        # under env_stage — the invariant above)
-        self.stats.tool_wait_slot_steps += sum(
-            1 for r in self._rows if r is not None and r.status == "calling")
-        for slot, r in enumerate(self._rows):
-            if r is None or r.status != "active" or advance[slot] == 0:
-                continue
-            if self.paged_kv:
-                self._slot_pos[slot] += 1     # device cache["pos"] mirror
-            was_forced = fmask[slot] == 1
-            if was_forced:
-                r.forced_q.pop(0)
-            action = r.accept(int(nxt[slot]), float(lp[slot]),
-                              0.0 if was_forced else 1.0, self.max_len)
-            self.stats.tokens_generated += 1
-            if not was_forced:
-                self.stats.sampled_tokens += 1
-            if action == "call":
-                self._on_call(slot)
-            elif action == "done":
-                self._evict(slot)
+        with phase("engine.wait", tr, STEP_TRACK) as ph_wait:
+            nxt = np.asarray(nxt)
+            lp = np.asarray(lp)
+        if self._t_read is not None:
+            self.stats.host_gap_seconds += ph.t0 - self._t_read
+            self.stats.host_gaps += 1
+        self._t_read = ph_wait.t1
+        self.stats.decode_seconds += ph_wait.t1 - ph.t0
+        with phase("engine.accept", tr, STEP_TRACK):
+            self.stats.decode_steps += 1
+            self.stats.occupied_row_steps += int(advance.sum())
+            self.stats.capacity_row_steps += self.max_slots
+            # slot dead weight of the freeze-in-slot baseline: resident
+            # rows that spent this decode step waiting on a tool (0 by
+            # construction under env_stage — the invariant above)
+            self.stats.tool_wait_slot_steps += sum(
+                1 for r in self._rows
+                if r is not None and r.status == "calling")
+            for slot, r in enumerate(self._rows):
+                if r is None or r.status != "active" or advance[slot] == 0:
+                    continue
+                if self.paged_kv:
+                    self._slot_pos[slot] += 1   # device cache["pos"] mirror
+                was_forced = fmask[slot] == 1
+                if was_forced:
+                    r.forced_q.pop(0)
+                action = r.accept(int(nxt[slot]), float(lp[slot]),
+                                  0.0 if was_forced else 1.0, self.max_len)
+                self.stats.tokens_generated += 1
+                if not was_forced:
+                    self.stats.sampled_tokens += 1
+                if action == "call":
+                    self._on_call(slot)
+                elif action == "done":
+                    self._evict(slot)
         return True
 
     def drain(self, deadline_s: float = 300.0,

@@ -71,6 +71,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 from repro.core.supervisor import join_or_raise
 from repro.envs.base import (CancelToken, PermanentToolError, ToolError,
                              TransientToolError, call_session)
+from repro.obs.phase import phase
 
 
 @dataclass
@@ -133,28 +134,29 @@ class EnvWorker(threading.Thread):
                 # the supervisor's recover_dead() re-queues it
                 self.chaos_killed = True
                 return
-            if job.latency > 0 and job.attempts == 0 \
-                    and not stage.sim_latency:
-                # interruptible: a timeout/abort wakes the worker NOW
-                # (retries skip the latency — the backoff already ran)
-                job.cancel.wait(job.latency)
-            resp: List[int] = []
-            try:
-                if not job.cancelled:
-                    stage._chaos_tool_fault(job)
-                    resp = list(call_session(job.row.session, job.query,
-                                             job.cancel))
-            except ToolError as e:
-                job.attempts += 1
-                if (isinstance(e, TransientToolError)
-                        and stage._schedule_retry(job)):
+            with phase("env.tool"):     # latency, call, response
+                if job.latency > 0 and job.attempts == 0 \
+                        and not stage.sim_latency:
+                    # interruptible: a timeout/abort wakes the worker NOW
+                    # (retries skip the latency — the backoff already ran)
+                    job.cancel.wait(job.latency)
+                resp: List[int] = []
+                try:
+                    if not job.cancelled:
+                        stage._chaos_tool_fault(job)
+                        resp = list(call_session(job.row.session, job.query,
+                                                 job.cancel))
+                except ToolError as e:
+                    job.attempts += 1
+                    if (isinstance(e, TransientToolError)
+                            and stage._schedule_retry(job)):
+                        continue
+                    job.error = e
+                    stage._finish(job, [])
                     continue
-                job.error = e
-                stage._finish(job, [])
-                continue
-            except BaseException as e:      # surfaced on the engine thread
-                job.error = e
-            stage._finish(job, resp)
+                except BaseException as e:  # surfaced on the engine thread
+                    job.error = e
+                stage._finish(job, resp)
 
 
 class EnvStage:

@@ -57,6 +57,7 @@ from repro.configs import ModelConfig
 from repro.lora.adapters import batched_ctx
 from repro.models import (forward_prefill_chunk, forward_seq, init_cache,
                           lm_logits)
+from repro.obs.phase import phase
 
 
 def _bucket_len(n: int) -> int:
@@ -261,6 +262,7 @@ class PrefillWorker(threading.Thread):
                     return True
             row = eng._sched.pop(eng.stats.refills, where=where)
             if row is not None:
+                eng._book_queue_wait(eng._sched.last_pushed_at)
                 eng._stage_inflight.append(row)
                 self.claimed.append(row)
         if row is not None and eng._tracer is not None:
@@ -381,7 +383,10 @@ class PrefillWorker(threading.Thread):
         try:
             while not eng._stage_stop.is_set():
                 self.last_beat = time.monotonic()
-                row = self._try_pop()
+                # profiler-only phases: the tracer already has a span per
+                # device call, with its episode's trace id (_advance)
+                with phase("prefill.pop"):
+                    row = self._try_pop()
                 if row is not None and eng._chaos is not None \
                         and eng._chaos.fire("prefill_worker_kill"):
                     # simulated abrupt death: skip the finally requeue —
@@ -408,7 +413,9 @@ class PrefillWorker(threading.Thread):
                     continue
                 job = jobs.popleft()         # round-robin: one chunk each
                 try:
-                    if not self._advance(job):
+                    with phase("prefill.chunk"):
+                        done = self._advance(job)
+                    if not done:
                         jobs.append(job)
                 except BaseException as e:   # surface to the engine thread
                     eng._stage_error = e

@@ -40,6 +40,7 @@ its stage lock.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -90,6 +91,7 @@ class _Entry:
                          # .sampled, .submit_index
     seq: int             # push order (FIFO key)
     enq_refill: int      # engine refill counter at push time (starvation age)
+    pushed_at: float     # host clock at push (the row's queue wait starts)
 
 
 class SlotScheduler:
@@ -108,13 +110,25 @@ class SlotScheduler:
         self.starvation_k = starvation_k
         self._entries: List[_Entry] = []
         self._seq = 0
+        self.last_pushed_at = 0.0   # push time of the row the last pop
+                                    # returned (its queue wait starts there)
 
     def __len__(self) -> int:
         return len(self._entries)
 
-    def push(self, row, refill_count: int = 0):
-        self._entries.append(_Entry(row, self._seq, refill_count))
+    def push(self, row, refill_count: int = 0,
+             pushed_at: Optional[float] = None):
+        """Queue a row. ``pushed_at`` keeps the push time of a row handed
+        back unused (pool pressure) so its queue wait runs on."""
+        self._entries.append(_Entry(
+            row, self._seq, refill_count,
+            time.monotonic() if pushed_at is None else pushed_at))
         self._seq += 1
+
+    def _take(self, i: int):
+        e = self._entries.pop(i)
+        self.last_pushed_at = e.pushed_at
+        return e.row
 
     def _key(self, e: _Entry, refill_count: int):
         if self.policy == "fifo":
@@ -145,7 +159,7 @@ class SlotScheduler:
             return None
         best = min(idxs,
                    key=lambda i: self._key(self._entries[i], refill_count))
-        return self._entries.pop(best).row
+        return self._take(best)
 
     def pop_if(self, refill_count: int = 0, pred=None):
         """Pop the highest-ranked row ONLY if it satisfies `pred`; returns
@@ -160,7 +174,7 @@ class SlotScheduler:
                    key=lambda i: self._key(self._entries[i], refill_count))
         if pred is not None and not pred(self._entries[best].row):
             return None
-        return self._entries.pop(best).row
+        return self._take(best)
 
     def pop_all(self) -> List:
         """Drain every queued row in current pop order (abort path)."""
