@@ -5,29 +5,31 @@ cache (the PagedAttention role proper; ``gqa_decode`` is its contiguous
 The cache is a pool of fixed-size pages ``[n_pages+1, page, KVH, hd]``
 shared by every decode slot; each row names its pages through a block
 table ``tbl: [B, max_pages]`` (entries are physical page ids; unused
-entries point at the scratch page ``n_pages``). One grid step = one
-(batch row, LOGICAL page): the block table rides the scalar-prefetch
-channel, so the BlockSpec ``index_map`` resolves logical page ``s`` of row
-``b`` to its physical page ``tbl[b, s]`` BEFORE the kernel body runs — the
-whole ``[page, KVH, hd]`` page tile (every KV head) is DMA'd straight from
-its pooled location in one contiguous copy, no gather materializes a
-contiguous cache. The block spans all KV heads because Mosaic requires a
-block's second-minor dimension to be a multiple of 8 or the full array
-dimension; a one-head block (``(1, page, 1, hd)``) is refused. Inside the
-body a static loop over the KVH groups runs the ``gqa_decode`` online
-(running max / sum / weighted-acc) softmax for the rep = H/KVH query heads
-sharing each KV head, with per-group VMEM scratch carried across logical
-pages; per-row valid length (`pos`), optional sliding window and gemma2's
-score softcap are applied per page.
+entries point at the scratch page ``n_pages``).
 
-Pages past a row's live count resolve to the scratch page (or any page —
-their positions are ≥ pos and fully masked), so a short row costs the
-same DMAs as dense only in grid steps, not in pool HBM: the pool holds
-Σ ceil(len_i / page) pages instead of B × max_len rows, which is the
-whole point: no per-slot max_len reservation.
+Each row's walk visits only its LIVE pages: ``ceil(pos / page)`` of them,
+or, under a static window, those from the first page inside the window
+on (``pos`` and the block table ride the scalar-prefetch channel). Block
+-table entries past a row's live count are never read, by DMA or by
+compute, and may hold any page id. The walk takes ``ppb`` pages per
+compute block: the smallest count with ``ppb·page ≥ 128`` tokens, so a
+score row fills the 128 lanes, capped by the table width.
 
-VMEM per step: 2·page·KVH·hd cache tile + H·hd acc — the same budget as
-``gqa_decode`` at bs == page.
+Per block, a static loop over the KV heads runs the ``gqa_decode`` online
+(running max / sum / weighted-acc, all float32) softmax for the rep =
+H/KVH query heads sharing each KV head, on ``[rep, ppb·page]`` scores.
+``q·k`` takes the operands in their own dtype (bf16 products are exact in
+float32) with float32 accumulation; ``p·v`` keeps ``p`` in float32. Per
+-row valid length (`pos`), optional sliding window and gemma2's score
+softcap are applied per block; a masked position takes no part whatever
+it holds (NaN included), and a row of length 0 reads nothing and returns
+zeros.
+
+The pipeline fetches the pages: grid (row, block), one block spec per
+page of a block, each resolved through the table. A block past the row's
+last live one repeats that block's page ids, and a page past the live
+count names the scratch page, so the pipeline fetches nothing for them;
+the step only pays the grid's own overhead.
 """
 from __future__ import annotations
 
@@ -39,49 +41,74 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
 
 
-def _paged_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, n_s, page, softcap, window,
-                  scale):
-    b = pl.program_id(0)
-    s = pl.program_id(1)
+def pages_per_block(page: int, max_pages: int) -> int:
+    """The smallest page count whose tokens fill the 128 lanes of a score
+    row, capped by the block-table width."""
+    return max(1, min(max_pages, -(-LANES // page)))
 
-    @pl.when(s == 0)
-    def _init():
+
+def _span(p, page, ppb, window):
+    """A row of length p: its first visited page, live page count and
+    block count."""
+    n_live = (p + page - 1) // page
+    first = jnp.maximum(p - window, 0) // page if window else 0
+    return first, n_live, (n_live - first + ppb - 1) // ppb
+
+
+def _kernel(pos_ref, tbl_ref, q_ref, *refs, page, ppb, softcap, window,
+            scale):
+    """Grid (row, block): the pipeline brings the block's pages, one block
+    spec each; blocks past the row's last live one compute nothing."""
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb:]
+    b, i = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+    first, _, n_blk = _span(pos, page, ppb, window)
+
+    @pl.when(i == 0)
+    def _():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[b]
-    for g in range(k_ref.shape[2]):                  # static: one KV head
-        q = q_ref[0, g].astype(jnp.float32)          # [rep, hd]
-        k = k_ref[0, :, g].astype(jnp.float32)       # [page, hd]
-        v = v_ref[0, :, g].astype(jnp.float32)       # [page, hd]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [rep, page]
-        if softcap:
-            scores = jnp.tanh(scores / softcap) * softcap
-        # logical (pre-paging) position of each lane in this page
-        idx = s * page + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    @pl.when(i < n_blk)
+    def _():
+        T = ppb * page
+        base = (first + i * ppb) * page
+        idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
         valid = idx < pos
         if window:
             valid &= (pos - 1 - idx) < window
-        scores = jnp.where(valid, scores, NEG_INF)
+        rows_valid = (base + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+                      < pos)
+        for g in range(q_ref.shape[1]):              # static: one KV head
+            k, v = (jnp.concatenate([r[0, :, g, :] for r in pages], axis=0)
+                    for pages in (k_refs, v_refs))   # each [T, hd]
+            q = q_ref[0, g]                          # [rep, hd]
+            cd = jnp.promote_types(q.dtype, k.dtype)
+            s = jax.lax.dot_general(
+                q.astype(cd), k.astype(cd), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [rep, T]
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(valid, s, NEG_INF)
+            # p is 0 on masked rows of V, and 0·NaN would still poison the sum
+            v = jnp.where(rows_valid, v.astype(jnp.float32), 0.0)
+            m_prev = m_ref[g]                        # [rep, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                   # [rep, T]
+            l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
 
-        m_prev = m_ref[g]                            # [rep, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)                  # [rep, page]
-        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[g] = m_new
-
-    @pl.when(s == n_s - 1)
-    def _flush():
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
@@ -97,35 +124,38 @@ def paged_gqa_decode(q, kp, vp, tbl, pos, *, softcap=0.0, window=0,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     B, H, hd = q.shape
-    page, KVH = kp.shape[1], kp.shape[2]
+    n_pages, page, KVH = kp.shape[0] - 1, kp.shape[1], kp.shape[2]
     n_s = tbl.shape[1]
     rep = H // KVH
-    scale = 1.0 / (hd ** 0.5)
+    ppb = pages_per_block(page, n_s)
+    kernel = functools.partial(_kernel, page=page, ppb=ppb, softcap=softcap,
+                               window=window, scale=1.0 / (hd ** 0.5))
+    row_spec = pl.BlockSpec((1, KVH, rep, hd),
+                            lambda b, i, p, t: (b, 0, 0, 0))
 
-    qg = q.reshape(B, KVH, rep, hd)
-    grid = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # pos, then the block table
-        grid=(B, n_s),
-        in_specs=[
-            pl.BlockSpec((1, KVH, rep, hd), lambda b, s, p, t: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, KVH, hd),
-                         lambda b, s, p, t: (t[b, s], 0, 0, 0)),
-            pl.BlockSpec((1, page, KVH, hd),
-                         lambda b, s, p, t: (t[b, s], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, KVH, rep, hd),
-                               lambda b, s, p, t: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((KVH, rep, 1), jnp.float32),
-            pltpu.VMEM((KVH, rep, 1), jnp.float32),
-            pltpu.VMEM((KVH, rep, hd), jnp.float32),
-        ],
-    )
+    def page_spec(j):
+        def index(b, i, p, t):
+            first, n_live, n_blk = _span(p[b], page, ppb, window)
+            s = first + jnp.minimum(i, jnp.maximum(n_blk - 1, 0)) * ppb + j
+            pid = t[b * n_s + jnp.minimum(s, n_s - 1)]
+            return jnp.where(s < n_live, pid, n_pages), 0, 0, 0
+        return pl.BlockSpec((1, page, KVH, hd), index)
+
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, n_s=n_s, page=page, softcap=softcap,
-                          window=window, scale=scale),
-        grid_spec=grid,
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,   # pos, then the flattened block table
+            grid=(B, -(-n_s // ppb)),
+            in_specs=[row_spec] + [page_spec(j) for j in range(ppb)] * 2,
+            out_specs=row_spec,
+            scratch_shapes=[pltpu.VMEM((KVH, rep, 1), jnp.float32),
+                            pltpu.VMEM((KVH, rep, 1), jnp.float32),
+                            pltpu.VMEM((KVH, rep, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, KVH, rep, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(pos.astype(jnp.int32), tbl.astype(jnp.int32), qg, kp, vp)
+        name="paged_gqa_decode",
+    )(pos.astype(jnp.int32), tbl.astype(jnp.int32).reshape(-1),
+      q.reshape(B, KVH, rep, hd), *(kp,) * ppb, *(vp,) * ppb)
     return out.reshape(B, H, hd)

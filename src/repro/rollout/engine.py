@@ -269,6 +269,14 @@ class RolloutStats:
                                        # to its row's return into a decode
                                        # slot
     resumes_installed: int = 0
+    # paged decode attention's reach (paged_kv), a property of the traffic:
+    # the pages the kernel at most has to visit, not a count of what it
+    # read (a static window visits fewer)
+    kv_live_pages: int = 0         # Σ over decode steps of the decoded
+                                   # rows' live pages, ceil(pos / page)
+                                   # with pos counting the step's token
+    kv_table_pages: int = 0        # Σ over decode steps of decoded rows ×
+                                   # block-table width
     env_wait_by_task: Dict[str, float] = field(default_factory=dict)
                                    # per-tenant env-interaction wait seconds
 
@@ -2877,6 +2885,12 @@ class ContinuousRolloutEngine:
             self.stats.decode_steps += 1
             self.stats.occupied_row_steps += int(advance.sum())
             self.stats.capacity_row_steps += self.max_slots
+            if self.paged_kv:
+                # this step's token lands at _slot_pos: ceil((pos+1)/page)
+                pos = np.asarray(self._slot_pos)[np.asarray(advance) > 0]
+                self.stats.kv_live_pages += int(
+                    (pos // self.kv_page_size + 1).sum())
+                self.stats.kv_table_pages += pos.size * self._max_pg
             # slot dead weight of the freeze-in-slot baseline: resident
             # rows that spent this decode step waiting on a tool (0 by
             # construction under env_stage — the invariant above)

@@ -107,6 +107,100 @@ def test_paged_gqa_decode_sweep(B, H, KVH, hd, n_pg, page, softcap, window):
                                rtol=2e-5, atol=2e-5)
 
 
+def _ragged_pool(key, pos, n_pg, page, KVH, hd, dtype, poison=False):
+    """Each row owns ceil(pos/page) shuffled pages; its table entries past
+    that point at pages it owns too (never read). With ``poison`` every
+    position at or past a row's pos, and every page only those entries
+    name, is NaN, and so is the scratch page."""
+    pos = np.asarray(pos)
+    B = len(pos)
+    live = -(-pos // page)
+    n_live, n_dead = int(live.sum()), int((n_pg - live).sum())
+    P = n_live + n_dead
+    ks = jax.random.split(key, 3)
+    kp = np.array(jax.random.normal(ks[0], (P + 1, page, KVH, hd),
+                                    jnp.float32))
+    vp = np.array(jax.random.normal(ks[1], (P + 1, page, KVH, hd),
+                                    jnp.float32))
+    perm = np.asarray(jax.random.permutation(ks[2], P))
+    tbl = np.zeros((B, n_pg), np.int32)
+    o_live, o_dead = 0, n_live
+    for b in range(B):
+        tbl[b, :live[b]] = perm[o_live:o_live + live[b]]
+        tbl[b, live[b]:] = perm[o_dead:o_dead + n_pg - live[b]]
+        o_live += live[b]
+        o_dead += n_pg - live[b]
+    if poison:
+        for b in range(B):
+            for s in range(n_pg):
+                lo = max(0, int(pos[b]) - s * page)
+                kp[tbl[b, s], lo:] = np.nan
+                vp[tbl[b, s], lo:] = np.nan
+        kp[P], vp[P] = np.nan, np.nan
+    return (jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+            jnp.asarray(tbl))
+
+
+# (pos per row, table width, page, window): pos of 1, on a page boundary,
+# on a block boundary (ppb·page = 128 tokens at pages of 16), one past it,
+# at the full table, rows of very different lengths, windows that start
+# inside a block, table widths that are not a multiple of ppb
+PAGED_EDGES = {
+    "pos_one": ([1, 1, 1], 8, 16, 0),
+    "page_boundary": ([16, 32, 48], 8, 16, 0),
+    "block_boundary": ([128, 129, 127], 16, 16, 0),
+    "full_table": ([128, 128, 127], 8, 16, 0),
+    "ragged": ([1, 500, 37, 256, 17], 32, 16, 0),
+    "window_mid_block": ([300, 171, 40], 20, 16, 200),
+    "window_tiny": ([299, 130, 3], 20, 16, 5),
+    "width_not_multiple": ([170, 100, 176], 11, 16, 0),
+    "width_not_multiple_page8": ([150, 61, 200], 25, 8, 0),
+    "one_page_blocks": ([300, 129, 5], 3, 128, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_EDGES))
+@pytest.mark.parametrize("poison", [False, True])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_gqa_decode_edges(case, poison, hd):
+    """Against the gather-then-dense oracle at the edges of the live-page
+    walk, for Granite's head size (64, lane-padded) and Qwen3's (128). With ``poison`` every position past a row's pos, the pages its
+    table names past its live count, and the scratch page hold NaN: none
+    of them may reach the output (the oracle reads the clean pool)."""
+    pos, n_pg, page, window = PAGED_EDGES[case]
+    B, H, KVH = len(pos), 4, 2
+    ks = jax.random.split(KEY, 2)
+    q = jax.random.normal(ks[0], (B, H, hd), jnp.float32)
+    kp, vp, tbl = _ragged_pool(ks[1], pos, n_pg, page, KVH, hd,
+                               jnp.float32)
+    want = ref.paged_gqa_decode_ref(q, kp, vp, tbl, jnp.asarray(pos),
+                                    window=window)
+    if poison:
+        kp, vp, tbl = _ragged_pool(ks[1], pos, n_pg, page, KVH, hd,
+                                   jnp.float32, poison=True)
+    out = paged_gqa_decode(q, kp, vp, tbl, jnp.asarray(pos), window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "granite-3-2b"])
+def test_paged_gqa_decode_model_heads_bf16(arch):
+    """The benchmark's head shapes (Qwen3 16/8 × 128, Granite 32/8 × 64) in
+    bf16, rows of ragged lengths over pages of 16."""
+    from repro.configs import REGISTRY
+    cfg = REGISTRY[arch]
+    H, KVH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    pos = [1, 70, 16, 129, 300, 512]
+    ks = jax.random.split(KEY, 2)
+    q = jax.random.normal(ks[0], (len(pos), H, hd), jnp.bfloat16)
+    kp, vp, tbl = _ragged_pool(ks[1], pos, 32, 16, KVH, hd, jnp.bfloat16)
+    out = paged_gqa_decode(q, kp, vp, tbl, jnp.asarray(pos))
+    want = ref.paged_gqa_decode_ref(q, kp, vp, tbl, jnp.asarray(pos))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=3e-2, atol=3e-2)
+
+
 def test_paged_gqa_decode_bf16_cache():
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, 4, 16), jnp.bfloat16)

@@ -384,6 +384,43 @@ def test_row_larger_than_pool_finishes_capacity():
     assert comps[0].finish_reason == "capacity"
 
 
+def test_kv_page_counters_count_what_the_decode_kernel_reads():
+    """Over every decode step, ``kv_live_pages`` sums each decoded row's
+    live pages, ceil(pos / page) with pos counting the step's own token,
+    and ``kv_table_pages`` sums decoded rows × block-table width: checked
+    against the pos and advance mask the device step is handed."""
+    cfg = tiny_lm("granite-3-2b")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    env = make_env("gsm8k")
+    rng = random.Random(5)
+    reqs = []
+    for i, n in enumerate([3, 9, 17, 26]):       # prompts across 1-4 pages
+        prompt, truth = env.sample_prompt(rng)
+        prompt = (prompt + [5] * n)[:n]
+        reqs.append(RolloutRequest("t0", 0, prompt, truth, env,
+                                   max_new_tokens=20, seed=i))
+    eng = ContinuousRolloutEngine(cfg, params, max_slots=4, max_adapters=1,
+                                  max_len=64, seed=0, paged_kv=True,
+                                  kv_page_size=8)
+    eng.set_adapters(0, init_lora(jax.random.PRNGKey(1), cfg))
+    seen = {"live": 0, "table": 0}
+    step_fn = eng._step_fn
+
+    def spy(*args):
+        cache, advance = args[4], np.asarray(args[10]) > 0
+        pos = np.asarray(cache["pos"])[advance] + 1
+        seen["live"] += int(np.sum(-(-pos // 8)))
+        seen["table"] += int(advance.sum()) * cache["tbl"].shape[1]
+        return step_fn(*args)
+
+    eng._step_fn = spy
+    _run_engine(eng, reqs)
+    st = eng.stats
+    assert st.decode_steps > 0 and 0 < seen["live"] < seen["table"]
+    assert st.kv_live_pages == seen["live"]
+    assert st.kv_table_pages == seen["table"] == st.occupied_row_steps * 8
+
+
 # ===========================================================================
 # 5. cooperative tool-call cancellation
 # ===========================================================================

@@ -1,11 +1,13 @@
 """Compile-only checks of the main path's Pallas kernels for a described
-(not attached) TPU v5e at Qwen3-0.6B's widths, plus token_logprob at
-granite's odd vocabulary. Nothing runs: the TPU compiler must accept each
+(not attached) TPU v5e at Qwen3-0.6B's widths, plus the paged decode
+kernel at Granite's head size of 64 and token_logprob at granite's odd
+vocabulary. Nothing runs: the TPU compiler must accept each
 kernel (Mosaic refuses misaligned blocks and vector loads from SMEM that
 interpret mode lets through) and leave a ``tpu_custom_call`` in the
 program. The topology is described inside a fixture, so a worker without
 libtpu skips these tests instead of failing at import."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,17 +50,29 @@ def _compile(fn, sharding, *shapes):
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
+
+
+def _compile_paged(cfg, sharding, batch, pool, max_pages):
+    H, KVH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    text = _compile(lambda q, k, v, t, p: paged_gqa_decode(q, k, v, t, p,
+                                                           interpret=False),
+                    sharding, ((batch, H, hd), jnp.bfloat16),
+                    ((pool + 1, PAGE, KVH, hd), jnp.bfloat16),
+                    ((pool + 1, PAGE, KVH, hd), jnp.bfloat16),
+                    ((batch, max_pages), jnp.int32), ((batch,), jnp.int32))
+    # the device trace finds the kernel by this name
+    assert re.search(r"%paged_gqa_decode[.\d]* = .*custom-call", text)
 
 
 def test_paged_gqa_decode_compiles(one_chip):
-    H, KVH, hd = QWEN.num_heads, QWEN.num_kv_heads, QWEN.head_dim
-    pool = B * MAX_LEN // PAGE
-    _compile(lambda q, k, v, t, p: paged_gqa_decode(q, k, v, t, p,
-                                                    interpret=False),
-             one_chip, ((B, H, hd), jnp.bfloat16),
-             ((pool + 1, PAGE, KVH, hd), jnp.bfloat16),
-             ((pool + 1, PAGE, KVH, hd), jnp.bfloat16),
-             ((B, MAX_LEN // PAGE), jnp.int32), ((B,), jnp.int32))
+    _compile_paged(QWEN, one_chip, B, B * MAX_LEN // PAGE, MAX_LEN // PAGE)
+
+
+def test_paged_gqa_decode_compiles_at_granite_widths(one_chip):
+    """Head size 64 (a lane-padded minor dimension) at the benchmark's
+    runtime shapes: 32 rows, 1024 pages, tables of 32."""
+    _compile_paged(REGISTRY["granite-3-2b"], one_chip, 32, 1024, 32)
 
 
 def test_gqa_decode_compiles(one_chip):
